@@ -19,7 +19,7 @@ import time
 from pathlib import Path
 
 # importing the API configures the persistent XLA compile cache
-# (repro.uvm.api.session.enable_compile_cache) before any jit runs
+# (repro.util.enable_compile_cache) before any jit runs
 from repro.uvm.api import ALL_BENCH, FEATURED, Session  # noqa: F401
 
 # Deprecated re-exports (PR 3 moved the configs to repro.configs.predictor_paper;

@@ -1,11 +1,4 @@
-"""JAX API compatibility shims.
-
-``jax.shard_map`` only exists as a top-level export on newer JAX; on the
-0.4.x line it lives in ``jax.experimental.shard_map`` with ``check_rep``
-instead of ``check_vma``. The pinned container ships 0.4.37, so the seed's
-``jax.shard_map`` call sites raised AttributeError in every multi-device
-test.
-"""
+"""Lane meshes for the lane-sharded simulator and trainer sweeps."""
 from __future__ import annotations
 
 import os
@@ -46,12 +39,3 @@ def lane_shardings(n_lanes: int):
 
     return NamedSharding(mesh, PartitionSpec("lanes")), NamedSharding(mesh, PartitionSpec())
 
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    kwargs = {} if check_vma is None else {"check_rep": check_vma}
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)

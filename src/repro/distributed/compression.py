@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
-
 
 def quantize(x: jax.Array, block: int = 256):
     """Symmetric per-block int8. Returns (q, scale, shape)."""
@@ -94,7 +92,7 @@ def make_compressed_allreduce(mesh, axis_name: str = "pod", block: int = 256):
             def f(x):
                 return compressed_psum(x, axis_name, block) / jax.lax.psum(1, axis_name)
 
-            return shard_map(f, mesh=mesh, in_specs=spec, out_specs=spec)(g)
+            return jax.shard_map(f, mesh=mesh, in_specs=spec, out_specs=spec)(g)
 
         return jax.tree.map(one, tree)
 

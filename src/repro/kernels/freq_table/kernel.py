@@ -13,6 +13,11 @@ the per-block loop oracle (first-hit way, first-empty way, lowest-counter
 eviction with first-on-ties, saturating +1).  ``lookup`` is one program
 gathering per-block rows with the same first-hit-way rule.
 
+The block stream (and the lookup's per-block output) lives in SMEM: the
+kernels read and write it one scalar at a time, which Mosaic allows from
+SMEM only.  SMEM holds 1 MiB on a v5e, so one call takes at most
+``MAX_STREAM`` blocks; :mod:`.ops` splits longer streams.
+
 ``interpret=True`` runs the identical program as jnp ops (CPU CI gate).
 """
 from __future__ import annotations
@@ -22,19 +27,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.policy import COUNTER_MAX
 
 _MAX_TILE = 128  # set-rows per program: 128 x 16 x int32 = 8KB per operand
+MAX_STREAM = 1 << 16  # blocks per call: 256 KiB of int32 in SMEM
 
 
 def _set_tile(n_sets: int) -> int:
-    if n_sets <= _MAX_TILE:
-        return n_sets
+    """Rows per program: the largest power-of-two divisor of ``n_sets`` up
+    to ``_MAX_TILE``, or the whole axis when that divisor is not a multiple
+    of the 8-row sublane tile."""
     tile = _MAX_TILE
     while n_sets % tile:
         tile //= 2
-    return tile
+    return tile if tile % 8 == 0 else n_sets
 
 
 def _update_kernel(blocks_ref, tags_ref, cnt_ref, out_tags_ref, out_cnt_ref,
@@ -86,7 +94,7 @@ def freq_update(tags, counters, blocks, *, interpret: bool = False):
         functools.partial(_update_kernel, n_sets=n_sets, tile=tile),
         grid=(n_sets // tile,),
         in_specs=[
-            pl.BlockSpec(blocks.shape, lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((tile, ways), lambda i: (i, 0)),
             pl.BlockSpec((tile, ways), lambda i: (i, 0)),
         ],
@@ -130,6 +138,9 @@ def freq_lookup(tags, counters, blocks, *, interpret: bool = False):
     n_sets = tags.shape[0]
     return pl.pallas_call(
         functools.partial(_lookup_kernel, n_sets=n_sets),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+        + [pl.BlockSpec(memory_space=pltpu.VMEM)] * 2,
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct(blocks.shape, jnp.int32),
         interpret=interpret,
     )(blocks, tags, counters)

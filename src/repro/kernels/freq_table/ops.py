@@ -29,11 +29,19 @@ def _pad_blocks(blocks, fill: int):
     return out, b.size
 
 
+def _chunks(b: np.ndarray) -> list[np.ndarray]:
+    """A padded stream in kernel-sized pieces (pow2 padding makes every
+    piece of a long stream exactly ``MAX_STREAM``, so one compile serves)."""
+    return [b[i : i + kernel.MAX_STREAM] for i in range(0, len(b), kernel.MAX_STREAM)]
+
+
 def freq_update(tags, counters, blocks, *, use_kernel=False, interpret=False):
     """Updated (tags, counters) after streaming ``blocks`` (any int dtype)."""
     b, _ = _pad_blocks(blocks, -1)
     if use_kernel:
-        return kernel.freq_update(tags, counters, b, interpret=interpret)
+        for chunk in _chunks(b):
+            tags, counters = kernel.freq_update(tags, counters, chunk, interpret=interpret)
+        return tags, counters
     return ref.freq_update_ref(tags, counters, b)
 
 
@@ -41,7 +49,8 @@ def freq_lookup(tags, counters, blocks, *, use_kernel=False, interpret=False):
     """Counter per block, -1 on miss (int32, same length as ``blocks``)."""
     b, n = _pad_blocks(blocks, -1)
     if use_kernel:
-        out = kernel.freq_lookup(tags, counters, b, interpret=interpret)
+        out = jnp.concatenate([kernel.freq_lookup(tags, counters, c, interpret=interpret)
+                               for c in _chunks(b)])
     else:
         out = ref.freq_lookup_ref(jnp.asarray(tags, jnp.int32),
                                   jnp.asarray(counters, jnp.int32), b)

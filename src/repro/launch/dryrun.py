@@ -124,10 +124,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Path, *, force
         except Exception as e:  # CPU backend may not implement it
             mem_rec = {"error": str(e)}
 
-        ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # jax 0.4.x returns one dict per device
-            ca = ca[0] if ca else {}
-        cost = dict(ca)
+        cost = dict(compiled.cost_analysis() or {})
         print({k: cost[k] for k in ("flops", "bytes accessed") if k in cost})
 
         tokens = shape.global_batch * (shape.seq_len if shape.kind != "decode" else 1)

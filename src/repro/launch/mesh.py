@@ -8,12 +8,20 @@ full placeholder fleet.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with Auto axes.  The sharding rules place arrays
+    with ``with_sharding_constraint``, which refuses the Explicit axes that
+    ``jax.make_mesh`` builds by default."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_mesh(spec: str):
@@ -24,7 +32,7 @@ def make_mesh(spec: str):
     else:
         dims = tuple(int(x) for x in spec.split("x"))
         axes = ("data", "model")[-len(dims):]
-    return jax.make_mesh(dims, axes)
+    return auto_mesh(dims, axes)
 
 
 # TPU v5e hardware constants (roofline targets; the container runs on CPU).

@@ -396,10 +396,7 @@ def analyze(compiled, mesh, model_flops: float, hlo_text: str | None = None, att
     pod = mesh.shape.get("pod", 1)
     per_pod = chips // pod if pod > 1 else chips
     hlo = hlo_text if hlo_text is not None else compiled.as_text()
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x returns one dict per device
-        ca = ca[0] if ca else {}
-    cost = dict(ca)
+    cost = dict(compiled.cost_analysis() or {})
     parsed = analyze_hlo(hlo, attn_score_trailing=attn_score_trailing)
 
     ici = dci = 0.0

@@ -42,7 +42,7 @@ def make_mesh_from_devices(prefer_model: int = 1):
         if d > 1 or a == "data":
             dims.append(d)
             axes.append(a)
-    return jax.make_mesh(tuple(dims), tuple(axes)), ctl
+    return meshmod.auto_mesh(tuple(dims), tuple(axes)), ctl
 
 
 def train_lm(args) -> dict:

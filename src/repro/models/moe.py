@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import pad_to
-from repro.distributed.compat import shard_map
 from repro.distributed.sharding import constrain
 from repro.models import layers as L
 from repro.models.params import Spec, prefix, subtree
@@ -128,7 +127,7 @@ def moe_ffn_ep(p, x, cfg, mesh):
         return y.reshape(b_l, s_l, D), aux
 
     xspec = P(batch_axes if len(batch_axes) > 1 else batch_axes[0], "model", None)
-    out = shard_map(
+    out = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(xspec, P(None, None), P("model", None, None), P("model", None, None), P("model", None, None)),

@@ -29,7 +29,6 @@ from __future__ import annotations
 import dataclasses
 import os
 import threading
-from pathlib import Path
 
 from repro.uvm.api.specs import (
     CellSpec,
@@ -45,23 +44,10 @@ from repro.uvm.api.specs import (
     SCALE_PRESETS,
 )
 from repro.uvm.api.store import RunStore
+from repro.util import enable_compile_cache
 
-
-def enable_compile_cache() -> None:
-    """Persistent XLA compilation cache: the simulator's unified scan and
-    the predictor's train/eval jits compile once per shape-bucket EVER, not
-    once per process. Harmless if the dir is unwritable (JAX falls back
-    silently)."""
-    import jax
-
-    cache_dir = os.environ.get("REPRO_JAX_CACHE", str(Path.home() / ".cache" / "repro_jax"))
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
-    except Exception:
-        pass
-
-
+# the simulator's scan and the predictor's train/eval jits compile once per
+# shape bucket per checkout, not once per process
 enable_compile_cache()
 
 from repro.configs.predictor_paper import CONFIG as PCFG_PAPER  # noqa: E402

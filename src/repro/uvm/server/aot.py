@@ -28,7 +28,7 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
-from jax import export as jax_export  # not reachable as `jax.export` on 0.4.x
+from jax import export as jax_export
 
 from repro.optim.adamw import OptState
 

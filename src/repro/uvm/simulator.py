@@ -624,13 +624,16 @@ def _jits_for(policy_fns: tuple, prefetch_fns: tuple, kernels: bool = False,
     return run_events, run_events_lanes, apply_prefetch
 
 
-def _jits(kernels: bool | None = None):
-    """Resolve the jit triple for the requested eviction path.
+def _use_kernels(kernels: bool | None) -> bool:
+    """``kernels=None`` reads :func:`sim_kernels_enabled` (the env default);
+    an explicit bool pins the path regardless of environment."""
+    return sim_kernels_enabled() if kernels is None else bool(kernels)
 
-    ``kernels=None`` reads :func:`sim_kernels_enabled` (the env default);
-    an explicit bool pins the path regardless of environment.  Interpret
+
+def _jits(kernels: bool | None = None):
+    """Resolve the jit triple for the requested eviction path.  Interpret
     mode is auto-selected per backend — callers never choose it."""
-    k = sim_kernels_enabled() if kernels is None else bool(kernels)
+    k = _use_kernels(kernels)
     return _jits_for(_registry.policy_branches(), _registry.prefetch_branches(),
                      k, _kernel_interpret() if k else False)
 
@@ -654,7 +657,8 @@ def _lane(tree, i):
 _INERT = ("lru", "demand")  # padding lane: huge capacity, cheapest policy
 
 
-def _shard_lanes(stacked: SimState, lane_arrays: tuple, rep_arrays: tuple, b_pad: int):
+def _shard_lanes(stacked: SimState, lane_arrays: tuple, rep_arrays: tuple, b_pad: int,
+                 kernels: bool | None = None):
     """Commit lane-stacked inputs to a cross-device lanes sharding.
 
     Lanes are fully independent, so GSPMD partitions the whole vmapped scan
@@ -663,9 +667,11 @@ def _shard_lanes(stacked: SimState, lane_arrays: tuple, rep_arrays: tuple, b_pad
     lane/device ratio, or with REPRO_SIM_SHARD=0 (checked inside
     :func:`lane_shardings`); any device_put failure (e.g. typed PRNG keys
     on an odd backend) falls back to unsharded execution — results are
-    bit-identical either way, lanes just stop overlapping across devices."""
+    bit-identical either way, lanes just stop overlapping across devices.
+    The compiled kernel path stays on one device too: GSPMD cannot
+    partition a Mosaic kernel (interpret mode, plain jnp ops, can be)."""
     lane_sh, rep_sh = lane_shardings(b_pad)
-    if lane_sh is None:
+    if lane_sh is None or (_use_kernels(kernels) and not _kernel_interpret()):
         return stacked, lane_arrays, rep_arrays
     try:
         st = jax.tree.map(lambda x: jax.device_put(x, lane_sh), stacked)
@@ -718,10 +724,11 @@ def _run_cells(
         ep = jnp.asarray(ep)
     evs = tuple(jnp.asarray(getattr(ev, f)) for f in ("blk", "nxt", "dt", "rl", "stride"))
     if ep is None:
-        stacked, (cap, pol, pf, nv), evs = _shard_lanes(_stack_states(states), (cap, pol, pf, nv), evs, b_pad)
+        stacked, (cap, pol, pf, nv), evs = _shard_lanes(
+            _stack_states(states), (cap, pol, pf, nv), evs, b_pad, kernels)
     else:
         stacked, (cap, pol, pf, nv, ep), evs = _shard_lanes(
-            _stack_states(states), (cap, pol, pf, nv, ep), evs, b_pad)
+            _stack_states(states), (cap, pol, pf, nv, ep), evs, b_pad, kernels)
     out_states, outs = _run_events(stacked, *evs, cap, pol, pf, nv, ep, kernels)
     return out_states, outs, b_real
 
@@ -1015,10 +1022,10 @@ def run_segments_many(
                     ep_np[j, : len(eps[i])] = np.asarray(eps[i], np.int32)
             ep = jnp.asarray(ep_np)
         if ep is None:
-            stacked, lane_arrs, _ = _shard_lanes(stacked, (*arrs, *cell_arr, nv), (), b_pad)
+            stacked, lane_arrs, _ = _shard_lanes(stacked, (*arrs, *cell_arr, nv), (), b_pad, kernels)
             *arrs, pol_a, pf_a, cap_a, nv = lane_arrs
         else:
-            stacked, lane_arrs, _ = _shard_lanes(stacked, (*arrs, *cell_arr, nv, ep), (), b_pad)
+            stacked, lane_arrs, _ = _shard_lanes(stacked, (*arrs, *cell_arr, nv, ep), (), b_pad, kernels)
             *arrs, pol_a, pf_a, cap_a, nv, ep = lane_arrs
         out_states, outs = _run_events_lanes(stacked, *arrs, cap_a, pol_a, pf_a, nv, ep, kernels)
         pdiv = np.asarray(outs["pfault"]).any(axis=1)

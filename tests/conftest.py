@@ -11,17 +11,11 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax  # noqa: E402
 
-# Share the persistent XLA compilation cache with benchmarks/ (same dir as
-# benchmarks.common): a test run pre-warms the simulator/predictor compiles,
-# so a benchmark run right after starts from warm executables.
-try:
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.environ.get("REPRO_JAX_CACHE", str(Path.home() / ".cache" / "repro_jax")),
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
-except Exception:
-    pass
+from repro.util import enable_compile_cache  # noqa: E402
+
+# The same persistent compile cache as every other entry point: a test run
+# pre-warms the simulator/predictor compiles for the benchmarks after it.
+enable_compile_cache()
 import pytest  # noqa: E402
 
 from repro.configs.base import ShapeConfig  # noqa: E402

@@ -420,3 +420,29 @@ def test_cli_run_and_sweep_share_store_keys(tmp_path, capsys):
     assert "computed=1" in capsys.readouterr().out
     assert cli.main(["run", "--benchmark", "ATAX", "--strategy", "uvmsmart"] + common) == 0
     assert "hits=1 computed=0" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("from_env", [False, True], ids=["checkout-default", "env-var"])
+def test_compile_cache_directory(tmp_path, from_env):
+    """Importing the API turns on the persistent compile cache: in
+    JAX_COMPILATION_CACHE_DIR when that is set (and compiled entries land
+    there), else in the fixed directory inside the checkout."""
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    from repro.util import CHECKOUT_CACHE_DIR
+
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = str(Path(__file__).resolve().parent.parent / "src")
+    want = str(CHECKOUT_CACHE_DIR)
+    code = "import repro.uvm.api, jax; print(jax.config.jax_compilation_cache_dir)"
+    if from_env:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+        code += ("\nfrom repro.uvm import simulator as S, trace as T"
+                 "\nS.run_batch(T.get_trace('ATAX', scale=0.1), [('lru', 'tree', 1.25)])")
+    out = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split()[0] == want
+    if from_env:
+        assert any((tmp_path / "cache").iterdir())

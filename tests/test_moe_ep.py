@@ -14,13 +14,14 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config
 from repro.distributed import sharding
+from repro.launch.mesh import auto_mesh
 from repro.models import moe
 from repro.models.params import init_params
 
 cfg = get_smoke_config("olmoe-1b-7b").replace(num_experts=8, top_k=2, capacity_factor=8.0)
 specs = moe.moe_specs(cfg)
 params = init_params(jax.random.key(0), specs, jnp.float32)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = auto_mesh((2, 4), ("data", "model"))
 x = jax.random.normal(jax.random.key(1), (4, 16, cfg.d_model), jnp.float32)
 
 with sharding.use_mesh_rules(mesh):
