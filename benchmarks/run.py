@@ -1,5 +1,4 @@
-"""Benchmark harness: one entry per paper table/figure (+ the roofline
-summary from the committed dry-run records).
+"""Benchmark harness: one entry per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run            # quick (reduced traces)
     PYTHONPATH=src python -m benchmarks.run --scale paper
@@ -10,34 +9,10 @@ Output: `name,us_per_call,derived` CSV lines + experiments/bench/<name>.csv.
 from __future__ import annotations
 
 import argparse
-import json
 import time
-from pathlib import Path
 
 from benchmarks import figures, tables
-from benchmarks.common import emit
 from repro.uvm.api import Session
-
-
-def roofline_summary(_ctx):
-    """Summarise the committed multi-pod dry-run (EXPERIMENTS.md source)."""
-    t0 = time.time()
-    d = Path("experiments/dryrun")
-    rows = []
-    if d.exists():
-        for f in sorted(d.glob("*__single.json")):
-            r = json.loads(f.read_text())
-            if r.get("status") != "ok":
-                rows.append({"arch": r["arch"], "shape": r["shape"], "bottleneck": r.get("reason", r["status"])[:40], "compute_s": "", "memory_s": "", "collective_s": "", "useful": ""})
-                continue
-            rl = r["roofline"]
-            rows.append({
-                "arch": r["arch"], "shape": r["shape"], "bottleneck": rl["bottleneck"],
-                "compute_s": f"{rl['compute_s']:.3e}", "memory_s": f"{rl['memory_s']:.3e}",
-                "collective_s": f"{rl['collective_s']:.3e}", "useful": round(rl["useful_ratio"], 2),
-            })
-    emit("roofline_summary", rows, t0)
-    return rows
 
 
 SUITES = {
@@ -58,11 +33,10 @@ SUITES = {
     "table8": tables.table8,
     "table9": tables.table9,
     "table10": tables.table10,
-    "roofline": roofline_summary,
 }
 
 # cheap first, NN-heavy later (shared caches warm up in order)
-ORDER = ["roofline", "table1", "table2", "table3", "table4", "fig3", "fig4", "fig6", "fig10", "fig11", "fig12", "table6", "fig13", "fig14", "table7", "table8", "table9", "table10"]
+ORDER = ["table1", "table2", "table3", "table4", "fig3", "fig4", "fig6", "fig10", "fig11", "fig12", "table6", "fig13", "fig14", "table7", "table8", "table9", "table10"]
 
 
 def main(argv=None) -> int:

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.predictor_paper import PredictorConfig
 from repro.core import losses
 from repro.core.baselines_nn import make_model
@@ -215,6 +216,7 @@ class Trainer:
         rows += [np.zeros(B, np.int64)] * (_pow2_rows(n_rows, 8) - n_rows)  # compile-bucket rows
         return np.stack(rows).astype(np.int32)
 
+    @obs.spanned("trainer.evaluate")
     def evaluate(self, params, fs: FeatureSet, n_active: int):
         """Top-1 correctness per sample + predicted class ids (all batches in
         one scanned dispatch; only the final padded batch carries junk rows,
@@ -222,10 +224,12 @@ class Trainer:
         n = len(fs)
         if n == 0:
             return np.zeros(0, bool), np.zeros(0, np.int32)
-        pidx = self._eval_schedule(n)
-        feats, labels = self._stage(fs)
-        cs, ps = self._eval_scan(params, feats, labels, jnp.asarray(pidx), n_active)
-        out = jax.device_get((cs, ps))  # one sync for the whole group
+        with obs.span("trainer.stage"):
+            pidx = jnp.asarray(self._eval_schedule(n))
+            feats, labels = self._stage(fs)
+        with obs.span("trainer.dispatch"):
+            cs, ps = self._eval_scan(params, feats, labels, pidx, n_active)
+        out = obs.to_host((cs, ps), "trainer.evaluate")  # one sync for the whole group
         correct = out[0].reshape(-1)[:n].astype(bool)
         pred = out[1].reshape(-1)[:n].astype(np.int32)
         return correct, pred
@@ -243,6 +247,7 @@ class Trainer:
         and dtype identical without inventing degenerate inputs)."""
         return lanes + [lanes[0]] * (b_pad - len(lanes))
 
+    @obs.spanned("trainer.evaluate")
     def evaluate_many(self, params_list: list, fs_list: list, n_active_list: list):
         """Batched :meth:`evaluate` across lanes (one model + feature group
         per lane — the cross-benchmark case).  Lanes are grouped by their
@@ -263,19 +268,21 @@ class Trainer:
                     results[i] = self.evaluate(params_list[i], fs_list[i], n_active_list[i])
                 continue
             idxs = [i for i, _ in lanes]
-            # device staging only happens once the bucket is known to vmap
-            staged = [(i, *self._stage(fs_list[i]), p) for i, p in lanes]
-            staged = self._pad_lanes(staged, _pow2_rows(len(staged), self.MIN_VMAP_LANES))
-            pidxs = [i for i, *_ in staged]
-            params = jax.tree.map(lambda *xs: jnp.stack(xs), *[params_list[i] for i in pidxs])
-            feats = {k: jnp.stack([f[k] for _, f, _, _ in staged]) for k in staged[0][1]}
-            labels = jnp.stack([l for _, _, l, _ in staged])
-            pidx = jnp.asarray(np.stack([p for _, _, _, p in staged]))
-            na = jnp.asarray(np.array([n_active_list[i] for i in pidxs], np.int32))
-            lanes = staged
-            params, feats, labels, pidx, na = _shard_lane_trees(len(lanes), params, feats, labels, pidx, na)
-            cs, ps = self._eval_scan_many(params, feats, labels, pidx, na)
-            out = jax.device_get((cs, ps))  # one sync per shape bucket
+            with obs.span("trainer.stage"):
+                # device staging only happens once the bucket is known to vmap
+                staged = [(i, *self._stage(fs_list[i]), p) for i, p in lanes]
+                staged = self._pad_lanes(staged, _pow2_rows(len(staged), self.MIN_VMAP_LANES))
+                pidxs = [i for i, *_ in staged]
+                params = jax.tree.map(lambda *xs: jnp.stack(xs), *[params_list[i] for i in pidxs])
+                feats = {k: jnp.stack([f[k] for _, f, _, _ in staged]) for k in staged[0][1]}
+                labels = jnp.stack([l for _, _, l, _ in staged])
+                pidx = jnp.asarray(np.stack([p for _, _, _, p in staged]))
+                na = jnp.asarray(np.array([n_active_list[i] for i in pidxs], np.int32))
+                lanes = staged
+                params, feats, labels, pidx, na = _shard_lane_trees(len(lanes), params, feats, labels, pidx, na)
+            with obs.span("trainer.dispatch"):
+                cs, ps = self._eval_scan_many(params, feats, labels, pidx, na)
+            out = obs.to_host((cs, ps), "trainer.evaluate_many")  # one sync per shape bucket
             for j, i in enumerate(idxs):
                 n = len(fs_list[i])
                 results[i] = (
@@ -314,6 +321,7 @@ class Trainer:
         et_np = np.asarray(in_et, bool)  # pad to the features' sample bucket
         return jnp.asarray(np.concatenate([et_np, np.zeros(_pow2_rows(n, 1024) - n, bool)]))
 
+    @obs.spanned("trainer.train_group")
     def train_group(self, entry: Entry, fs: FeatureSet, n_active: int, *, in_et=None, use_lucir=False, rng=None):
         """Fine-tune on one group (a few epochs) in ONE scanned dispatch."""
         tc = self.tcfg
@@ -324,20 +332,23 @@ class Trainer:
             return entry
         rng = np.random.default_rng(tc.seed if rng is None else rng)
         use_l = use_lucir and entry.prev_params is not None
-        idx_mat, valid, n_steps = self._train_schedule(n, rng)
-        feats, labels = self._stage(fs)
-        et = self._stage_et(in_et, n)
+        with obs.span("trainer.stage"):
+            idx_mat, valid, n_steps = self._train_schedule(n, rng)
+            feats, labels = self._stage(fs)
+            et = self._stage_et(in_et, n)
+            step0, na = jnp.asarray(entry.step, jnp.int32), jnp.asarray(n_active, jnp.int32)
+            idx_mat, valid = jnp.asarray(idx_mat), jnp.asarray(valid)
         prev = entry.prev_params if use_l else entry.params  # ignored unless use_lucir
-        entry.params, entry.opt_state = self._train_scan(
-            entry.params, entry.opt_state, jnp.asarray(entry.step, jnp.int32),
-            feats, labels, et, prev, jnp.asarray(idx_mat), jnp.asarray(valid),
-            jnp.asarray(n_active, jnp.int32),
-            use_lucir=use_l, use_thrash=in_et is not None,
-        )
+        with obs.span("trainer.dispatch"):
+            entry.params, entry.opt_state = self._train_scan(
+                entry.params, entry.opt_state, step0, feats, labels, et, prev, idx_mat, valid, na,
+                use_lucir=use_l, use_thrash=in_et is not None,
+            )
         entry.step += n_steps
         entry.n_updates += 1
         return entry
 
+    @obs.spanned("trainer.train_group")
     def train_group_many(self, entries: list, fs_list: list, n_active_list: list, *, in_et_list=None, use_lucir=False):
         """Batched :meth:`train_group` across lanes (one entry + group per
         lane).  Lanes are grouped by (sample bucket, step bucket, LUCIR
@@ -368,30 +379,32 @@ class Trainer:
                     )
                 continue
             idxs = [i for i, *_ in lanes]
-            lanes = [
-                (i, *self._stage(fs_list[i]), self._stage_et(in_et_list[i], len(fs_list[i])), m, v, s)
-                for i, m, v, s in lanes
-            ]
-            lanes = self._pad_lanes(lanes, _pow2_rows(len(lanes), self.MIN_VMAP_LANES))
-            pidxs = [i for i, *_ in lanes]
-            stack = lambda xs: jax.tree.map(lambda *a: jnp.stack(a), *xs)
-            params = stack([entries[i].params for i in pidxs])
-            opt_state = stack([entries[i].opt_state for i in pidxs])
-            prev = stack([entries[i].prev_params if use_l else entries[i].params for i in pidxs])
-            step0 = jnp.asarray(np.array([entries[i].step for i in pidxs], np.int32))
-            feats = {k: jnp.stack([f[k] for _, f, *_ in lanes]) for k in lanes[0][1]}
-            labels = jnp.stack([l for _, _, l, *_ in lanes])
-            et = jnp.stack([e for _, _, _, e, *_ in lanes])
-            idx_mat = jnp.asarray(np.stack([m for _, _, _, _, m, _, _ in lanes]))
-            valid = jnp.asarray(np.stack([v for _, _, _, _, _, v, _ in lanes]))
-            na = jnp.asarray(np.array([n_active_list[i] for i in pidxs], np.int32))
-            params, opt_state, step0, feats, labels, et, prev, idx_mat, valid, na = _shard_lane_trees(
-                len(lanes), params, opt_state, step0, feats, labels, et, prev, idx_mat, valid, na,
-            )
-            new_params, new_opt = self._train_scan_many(
-                params, opt_state, step0, feats, labels, et, prev, idx_mat, valid, na,
-                use_lucir=use_l, use_thrash=use_thrash,
-            )
+            with obs.span("trainer.stage"):
+                lanes = [
+                    (i, *self._stage(fs_list[i]), self._stage_et(in_et_list[i], len(fs_list[i])), m, v, s)
+                    for i, m, v, s in lanes
+                ]
+                lanes = self._pad_lanes(lanes, _pow2_rows(len(lanes), self.MIN_VMAP_LANES))
+                pidxs = [i for i, *_ in lanes]
+                stack = lambda xs: jax.tree.map(lambda *a: jnp.stack(a), *xs)
+                params = stack([entries[i].params for i in pidxs])
+                opt_state = stack([entries[i].opt_state for i in pidxs])
+                prev = stack([entries[i].prev_params if use_l else entries[i].params for i in pidxs])
+                step0 = jnp.asarray(np.array([entries[i].step for i in pidxs], np.int32))
+                feats = {k: jnp.stack([f[k] for _, f, *_ in lanes]) for k in lanes[0][1]}
+                labels = jnp.stack([l for _, _, l, *_ in lanes])
+                et = jnp.stack([e for _, _, _, e, *_ in lanes])
+                idx_mat = jnp.asarray(np.stack([m for _, _, _, _, m, _, _ in lanes]))
+                valid = jnp.asarray(np.stack([v for _, _, _, _, _, v, _ in lanes]))
+                na = jnp.asarray(np.array([n_active_list[i] for i in pidxs], np.int32))
+                params, opt_state, step0, feats, labels, et, prev, idx_mat, valid, na = _shard_lane_trees(
+                    len(lanes), params, opt_state, step0, feats, labels, et, prev, idx_mat, valid, na,
+                )
+            with obs.span("trainer.dispatch"):
+                new_params, new_opt = self._train_scan_many(
+                    params, opt_state, step0, feats, labels, et, prev, idx_mat, valid, na,
+                    use_lucir=use_l, use_thrash=use_thrash,
+                )
             # only the real lanes (padding replicas of lane 0 are discarded)
             for j, (i, *_, n_steps) in zip(range(len(idxs)), lanes):
                 entries[i].params = jax.tree.map(lambda x: x[j], new_params)

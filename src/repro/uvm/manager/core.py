@@ -42,6 +42,7 @@ import time
 
 import numpy as np
 
+from repro import obs
 from repro.configs.predictor_paper import CONFIG_QUICK, PredictorConfig
 from repro.core.features import DeltaVocab, FeatureSet
 from repro.core.incremental import Entry, TrainConfig, Trainer
@@ -386,6 +387,7 @@ class OversubscriptionManager:
 
     # -- streaming protocol --------------------------------------------------
 
+    @obs.spanned("manager.observe")
     def observe(self, batch: FaultBatch) -> Actions:
         """One full round: ingest a fault batch, return the engine's actions."""
         req = self.observe_begin(batch)
@@ -404,6 +406,7 @@ class OversubscriptionManager:
                     corr = pred = None
         return self.observe_finish(corr, pred)
 
+    @obs.spanned("manager.feedback")
     def feedback(self, outcomes: Outcomes) -> None:
         """Close the last observed batch: flush cadence + causal fine-tune."""
         req = self.feedback_begin(outcomes)
@@ -428,13 +431,15 @@ class OversubscriptionManager:
         if self._pending is not None:
             raise RuntimeError("observe() called twice without feedback()")
         batch = batch if isinstance(batch, FaultBatch) else FaultBatch(np.asarray(batch))
-        g0, g1 = self.stream.append(batch.page, batch.pc, batch.tb)
-        fs = self.stream.windows(g0, g1)
+        with obs.span("manager.stream"):
+            g0, g1 = self.stream.append(batch.page, batch.pc, batch.tb)
+            fs = self.stream.windows(g0, g1)
         blocks = (np.asarray(batch.page, np.int64) // self.cfg.pages_per_block)
-        if self.cfg.reclass_interval > 0:
-            pat = self._reclassify(blocks, batch.kernel)
-        else:
-            pat = self.classifier.classify(blocks, batch.kernel)
+        with obs.span("manager.classify"):
+            if self.cfg.reclass_interval > 0:
+                pat = self._reclassify(blocks, batch.kernel)
+            else:
+                pat = self.classifier.classify(blocks, batch.kernel)
         entry = self.table.get(pat)
         self._pending = _Pending(
             g0=g0, n=g1 - g0, fs=fs, pat=pat, entry=entry,
@@ -466,34 +471,36 @@ class OversubscriptionManager:
         if p.fallback:
             self.n_fallbacks += 1
             return self._fallback_actions(p)
-        counters = None
-        prefetch = np.zeros(0, np.int64)
-        accuracy = None
-        if corr is not None and len(p.fs):
-            accuracy = float(corr.mean())
-            self.per_group.append(accuracy)
-            self._corr_true += int(np.count_nonzero(corr))
-            self._corr_n += len(corr)
-            if p.entry.n_updates > 0:
-                self._warm_true += int(np.count_nonzero(corr))
-                self._warm_n += len(corr)
-            self.n_predictions += len(p.fs)
-            p.entry.last_acc = accuracy  # informs the NEXT group's gate
-            # predicted classes -> raw deltas -> predicted pages
-            pred_delta = self._decode_deltas(pred_cls)
-            prev_page = self.stream.page_at(p.fs.t_index - 1).astype(np.int64)
-            pred_pages = np.clip(prev_page + pred_delta, 0, self.cfg.n_pages - 1)
-            if p.warm:
-                self.freq_table.update(np.asarray(pred_pages, np.int64) // self.cfg.pages_per_block)
-                # one dense export per batch: it feeds both the simulator's
-                # `learned` eviction keys and the prefetch gate
-                counters = self.freq_table.dense(self.cfg.n_blocks)
-                mask = prefetch_mask(
-                    counters, pred_pages, p.entry.last_acc,
-                    self.cfg.n_blocks, self.cfg.capacity, self.cfg.pages_per_block,
-                )
-                prefetch = np.flatnonzero(mask)
-                self._chain_li[prefetch] = self._interval  # staged = touched
+        with obs.span("manager.policy"):
+            counters = None
+            prefetch = np.zeros(0, np.int64)
+            accuracy = None
+            if corr is not None and len(p.fs):
+                accuracy = float(corr.mean())
+                self.per_group.append(accuracy)
+                self._corr_true += int(np.count_nonzero(corr))
+                self._corr_n += len(corr)
+                if p.entry.n_updates > 0:
+                    self._warm_true += int(np.count_nonzero(corr))
+                    self._warm_n += len(corr)
+                self.n_predictions += len(p.fs)
+                p.entry.last_acc = accuracy  # informs the NEXT group's gate
+                # predicted classes -> raw deltas -> predicted pages
+                pred_delta = self._decode_deltas(pred_cls)
+                prev_page = self.stream.page_at(p.fs.t_index - 1).astype(np.int64)
+                pred_pages = np.clip(prev_page + pred_delta, 0, self.cfg.n_pages - 1)
+                if p.warm:
+                    self.freq_table.update(np.asarray(pred_pages, np.int64) // self.cfg.pages_per_block)
+                    # one dense export per batch: it feeds both the simulator's
+                    # `learned` eviction keys and the prefetch gate
+                    counters = self.freq_table.dense(self.cfg.n_blocks)
+                    mask = prefetch_mask(
+                        counters, pred_pages, p.entry.last_acc,
+                        self.cfg.n_blocks, self.cfg.capacity, self.cfg.pages_per_block,
+                    )
+                    prefetch = np.flatnonzero(mask)
+                    self._chain_li[prefetch] = self._interval  # staged = touched
+            pre_evict = self._pre_evict(counters)
         if (
             self.cfg.health is not None
             and self._health_state == "recovering"
@@ -506,7 +513,7 @@ class OversubscriptionManager:
                 self.n_recoveries += 1
         return Actions(
             prefetch_blocks=prefetch,
-            pre_evict_blocks=self._pre_evict(counters),
+            pre_evict_blocks=pre_evict,
             counters=counters,
             pattern=p.pat,
             accuracy=accuracy,

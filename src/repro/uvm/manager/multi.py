@@ -50,6 +50,7 @@ import pickle
 
 import numpy as np
 
+from repro import obs
 from repro.core.incremental import Trainer
 from repro.core.model_table import ModelTable
 from repro.uvm import registry as _registry
@@ -277,6 +278,7 @@ class TenantMux:
 
     # -- streaming protocol --------------------------------------------------
 
+    @obs.spanned("manager.observe")
     def observe(self, batch: FaultBatch) -> MuxActions:
         """One full round: demux, per-tenant classify, ONE batched predictor
         dispatch, combined actions.  With ``cfg.health`` set, each tenant's
@@ -329,6 +331,7 @@ class TenantMux:
             [next(results) if (r is not None and id(r) in dispatched) else None for _, r in pairs]
         )
 
+    @obs.spanned("manager.feedback")
     def feedback(self, outcomes: Outcomes, *, tenant=_UNSET) -> None:
         """Close the last round (or one tenant's pending batch): split the
         outcome report, advance every observed tenant's fault clock, batch
@@ -404,15 +407,16 @@ class TenantMux:
             per_tenant[k] = actions
             if actions.accuracy is not None:
                 self.per_group.append(actions.accuracy)
-        warm_any = any(a.counters is not None for a in per_tenant.values())
-        counters = self._combined_dense() if warm_any else None
-        return MuxActions(
-            per_tenant=per_tenant,
-            prefetch_blocks=_stable_unique([a.prefetch_blocks for a in per_tenant.values()]),
-            counters=counters,
-            pre_evict_blocks=_round_robin([a.pre_evict_blocks for a in per_tenant.values()]),
-            budgets=dict(self.qos.budgets) if self.qos is not None else None,
-        )
+        with obs.span("manager.combine"):
+            warm_any = any(a.counters is not None for a in per_tenant.values())
+            counters = self._combined_dense() if warm_any else None
+            return MuxActions(
+                per_tenant=per_tenant,
+                prefetch_blocks=_stable_unique([a.prefetch_blocks for a in per_tenant.values()]),
+                counters=counters,
+                pre_evict_blocks=_round_robin([a.pre_evict_blocks for a in per_tenant.values()]),
+                budgets=dict(self.qos.budgets) if self.qos is not None else None,
+            )
 
     def feedback_begin(self, outcomes: Outcomes, *, tenant=_UNSET) -> list[tuple[object, TrainRequest | None]]:
         """Split the outcome report along the last round's partition (or

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.predictor_paper import PredictorConfig
 from repro.core.features import DeltaVocab, FeatureStream
 from repro.core.incremental import TrainConfig, Trainer
@@ -292,7 +293,8 @@ def manager_for(
         reclass_interval=reclass_interval, reclass_hysteresis=reclass_hysteresis,
         health=health,
     )
-    return OversubscriptionManager(cfg, table=table)
+    with obs.span("runtime.new_manager"):
+        return OversubscriptionManager(cfg, table=table)
 
 
 def mux_for(
@@ -335,10 +337,11 @@ def mux_for(
     if qos is not None and hasattr(qos, "controller"):  # a QosSpec
         qos = qos.controller(cfg.capacity, cfg.n_blocks, trace.tenant_names)
     tenants = [int(t) for t in np.unique(trace.tenant)]
-    return TenantMux(
-        cfg, tenants, shared_freq_table=shared_freq_table, auto_create=False,
-        tables=table, trainer=trainer, qos=qos,
-    )
+    with obs.span("runtime.new_manager"):
+        return TenantMux(
+            cfg, tenants, shared_freq_table=shared_freq_table, auto_create=False,
+            tables=table, trainer=trainer, qos=qos,
+        )
 
 
 def _group_batch(trace: Trace, g0: int, g1: int) -> FaultBatch:
@@ -356,22 +359,24 @@ def _apply_actions(state, actions, nb: int, cap: int, evict_pref=None):
     evictions respect the budgets exactly as demand evictions do."""
     if actions.counters is None:
         return state
-    state = state._replace(freq=jnp.asarray(actions.counters))
-    mask = np.zeros(nb, bool)
-    mask[actions.prefetch_blocks] = True
-    return S.apply_prefetch(
-        state, jnp.asarray(mask), capacity=cap, policy="learned",
-        evict_pref=evict_pref,
-    )
+    with obs.span("runtime.apply_actions"):
+        state = state._replace(freq=jnp.asarray(actions.counters))
+        mask = np.zeros(nb, bool)
+        mask[actions.prefetch_blocks] = True
+        return S.apply_prefetch(
+            state, jnp.asarray(mask), capacity=cap, policy="learned",
+            evict_pref=evict_pref,
+        )
 
 
 def _state_stats(state) -> dict:
+    pull = lambda x: int(obs.to_host(x, "runtime.stats"))
     return {
-        "pages_thrashed": int(state.thrash_events) * PAGES_PER_BLOCK,
-        "faults": int(state.faults),
-        "migrated_blocks": int(state.migrations),
-        "zero_copy": int(state.zero_copy),
-        "occupancy": int(state.occupancy),
+        "pages_thrashed": pull(state.thrash_events) * PAGES_PER_BLOCK,
+        "faults": pull(state.faults),
+        "migrated_blocks": pull(state.migrations),
+        "zero_copy": pull(state.zero_copy),
+        "occupancy": pull(state.occupancy),
     }
 
 
@@ -506,25 +511,26 @@ def run_ours(
     G = mgr.cfg.train.group_size
     for g0 in range(0, n, G):
         g1 = min(g0 + G, n)
-        actions = mgr.observe(_group_batch(trace, g0, g1))
-        # the QoS leading victim key for this segment: budgets vs CURRENT
-        # residency (None on budget-free runs = the exact pre-QoS program)
-        ep = (
-            mgr.evict_pref(np.asarray(state.resident))
-            if isinstance(mgr, TenantMux) else None
-        )
-        state = _apply_actions(state, actions, nb, cap, evict_pref=ep)
-        state, outs = S.run_segment(
-            state, blocks[g0:g1], nxt[g0:g1],
-            capacity=cap, policy="learned", prefetch="demand", n_valid=trace.n_blocks,
-            evict_pref=ep,
-        )
-        mgr.feedback(Outcomes(
-            was_evicted=np.asarray(outs["was_evicted"]),
-            fault_count=int(state.fault_count),
-        ))
-        if ledger is not None:
-            ledger.account(g0, g1, outs)
+        with obs.span("runtime.round", round=g0 // G):
+            actions = mgr.observe(_group_batch(trace, g0, g1))
+            # the QoS leading victim key for this segment: budgets vs CURRENT
+            # residency (None on budget-free runs = the exact pre-QoS program)
+            ep = (
+                mgr.evict_pref(obs.to_host(state.resident, "runtime.resident"))
+                if isinstance(mgr, TenantMux) else None
+            )
+            state = _apply_actions(state, actions, nb, cap, evict_pref=ep)
+            state, outs = S.run_segment(
+                state, blocks[g0:g1], nxt[g0:g1],
+                capacity=cap, policy="learned", prefetch="demand", n_valid=trace.n_blocks,
+                evict_pref=ep,
+            )
+            mgr.feedback(Outcomes(
+                was_evicted=np.asarray(outs["was_evicted"]),
+                fault_count=int(obs.to_host(state.fault_count, "runtime.fault_count")),
+            ))
+            if ledger is not None:
+                ledger.account(g0, g1, outs)
     return _result(mgr, state, n, None if ledger is None else ledger.result())
 
 
@@ -642,61 +648,62 @@ def run_ours_many(
     G = tcfg.group_size
     max_n = max((len(l.trace) for l in lanes), default=0)
     for g0 in range(0, max_n, G):
-        act = [l for l in lanes if g0 < len(l.trace)]
-        # 1. observe every lane's group; the predictor dispatches batch
-        #    through one vmapped evaluate per shape bucket (mux lanes fan
-        #    out one request per tenant into the same dispatch)
-        reqs = [
-            (l, l.observe_begin_all(_group_batch(l.trace, g0, min(g0 + G, len(l.trace)))))
-            for l in act
-        ]
-        flat = [r for _, rs in reqs for r in rs if r is not None]
-        results = iter(trainer.evaluate_many(
-            [r.params for r in flat], [r.fs for r in flat], [r.n_active for r in flat],
-        ))
-        for l, rs in reqs:
-            actions = l.observe_finish_all([next(results) if r is not None else None for r in rs])
-            # the lane's QoS leading victim key for this segment (None on
-            # budget-free lanes = the exact pre-QoS vmapped program)
-            l.ep = (
-                l.mgr.evict_pref(np.asarray(l.state.resident))
-                if isinstance(l.mgr, TenantMux) else None
-            )
-            # 2. stage counters + prefetches into the lane's simulator state
-            l.state = _apply_actions(
-                l.state, actions, l.mgr.cfg.n_blocks, l.mgr.cfg.capacity,
-                evict_pref=l.ep,
-            )
+        with obs.span("runtime.round", round=g0 // G):
+            act = [l for l in lanes if g0 < len(l.trace)]
+            # 1. observe every lane's group; the predictor dispatches batch
+            #    through one vmapped evaluate per shape bucket (mux lanes fan
+            #    out one request per tenant into the same dispatch)
+            reqs = [
+                (l, l.observe_begin_all(_group_batch(l.trace, g0, min(g0 + G, len(l.trace)))))
+                for l in act
+            ]
+            flat = [r for _, rs in reqs for r in rs if r is not None]
+            results = iter(trainer.evaluate_many(
+                [r.params for r in flat], [r.fs for r in flat], [r.n_active for r in flat],
+            ))
+            for l, rs in reqs:
+                actions = l.observe_finish_all([next(results) if r is not None else None for r in rs])
+                # the lane's QoS leading victim key for this segment (None on
+                # budget-free lanes = the exact pre-QoS vmapped program)
+                l.ep = (
+                    l.mgr.evict_pref(obs.to_host(l.state.resident, "runtime.resident"))
+                    if isinstance(l.mgr, TenantMux) else None
+                )
+                # 2. stage counters + prefetches into the lane's simulator state
+                l.state = _apply_actions(
+                    l.state, actions, l.mgr.cfg.n_blocks, l.mgr.cfg.capacity,
+                    evict_pref=l.ep,
+                )
 
-        # 3. simulator segments under the learned policy, vmapped across
-        #    lanes (each lane has its own compressed event stream)
-        seg = S.run_segments_many(
-            [l.state for l in act],
-            [(l.blocks[g0:min(g0 + G, len(l.trace))], l.nxt[g0:min(g0 + G, len(l.trace))]) for l in act],
-            [(S.POLICY_IDS["learned"], S.PREFETCH_IDS["demand"], l.mgr.cfg.capacity) for l in act],
-            [l.trace.n_blocks for l in act],
-            evict_prefs=[l.ep for l in act],
-        )
-        # 4. feedback; the fine-tune dispatches batch through one vmapped
-        #    train per bucket, then every manager publishes its entry
-        treqs = []
-        for l, (state, outs) in zip(act, seg):
-            l.state = state
-            treqs.append((l, l.feedback_begin_all(Outcomes(
-                was_evicted=np.asarray(outs["was_evicted"]),
-                fault_count=int(state.fault_count),
-            )), outs))
-        tflat = [r for _, rs, _ in treqs for r in rs if r is not None]
-        trainer.train_group_many(
-            [r.entry for r in tflat], [r.fs for r in tflat], [r.n_active for r in tflat],
-            in_et_list=[r.in_et for r in tflat], use_lucir=use_lucir,
-        )
-        for l, rs, outs in treqs:
-            l.feedback_finish_all(rs)
-            # fairness accounting + QoS tenant departure, after the round
-            # fully closes — same ordering as the serial run_ours loop
-            if l.ledger is not None:
-                l.ledger.account(g0, min(g0 + G, len(l.trace)), outs)
+            # 3. simulator segments under the learned policy, vmapped across
+            #    lanes (each lane has its own compressed event stream)
+            seg = S.run_segments_many(
+                [l.state for l in act],
+                [(l.blocks[g0:min(g0 + G, len(l.trace))], l.nxt[g0:min(g0 + G, len(l.trace))]) for l in act],
+                [(S.POLICY_IDS["learned"], S.PREFETCH_IDS["demand"], l.mgr.cfg.capacity) for l in act],
+                [l.trace.n_blocks for l in act],
+                evict_prefs=[l.ep for l in act],
+            )
+            # 4. feedback; the fine-tune dispatches batch through one vmapped
+            #    train per bucket, then every manager publishes its entry
+            treqs = []
+            for l, (state, outs) in zip(act, seg):
+                l.state = state
+                treqs.append((l, l.feedback_begin_all(Outcomes(
+                    was_evicted=np.asarray(outs["was_evicted"]),
+                    fault_count=int(obs.to_host(state.fault_count, "runtime.fault_count")),
+                )), outs))
+            tflat = [r for _, rs, _ in treqs for r in rs if r is not None]
+            trainer.train_group_many(
+                [r.entry for r in tflat], [r.fs for r in tflat], [r.n_active for r in tflat],
+                in_et_list=[r.in_et for r in tflat], use_lucir=use_lucir,
+            )
+            for l, rs, outs in treqs:
+                l.feedback_finish_all(rs)
+                # fairness accounting + QoS tenant departure, after the round
+                # fully closes — same ordering as the serial run_ours loop
+                if l.ledger is not None:
+                    l.ledger.account(g0, min(g0 + G, len(l.trace)), outs)
 
     return [
         _result(l.mgr, l.state, len(l.trace),

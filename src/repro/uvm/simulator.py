@@ -87,6 +87,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.distributed.compat import lane_shardings
 from repro.util import pow2_bucket
 from repro.uvm import registry as _registry
@@ -650,7 +651,9 @@ def _stack_states(states: list[SimState]) -> SimState:
     return jax.tree.map(lambda *xs: jnp.stack(xs), *states)
 
 
+@obs.spanned("simulator.unstage")
 def _lane(tree, i):
+    """Lane ``i`` of a lane-stacked tree (one small device slice per leaf)."""
     return jax.tree.map(lambda x: x[i], tree)
 
 
@@ -704,32 +707,34 @@ def _run_cells(
     padding blocks, which are never resident and so never candidates
     (tests/test_properties.py::test_evict_pref_padding_invariant pins
     this against mixed negative/``None``-interleaved lanes)."""
-    n_blocks = states[0].resident.shape[0]
-    b_real = len(cells)
-    # lane buckets {1, 8, 16, ...}: single runs stay cheap, sweeps share compiles
-    b_pad = 1 if b_real == 1 else _bucket_pow2(b_real, 8)
-    cells = list(cells) + [(POLICY_IDS[_INERT[0]], PREFETCH_IDS[_INERT[1]], n_blocks + 1)] * (b_pad - b_real)
-    states = states + [init_state(n_blocks)] * (b_pad - b_real)
-    ev = _pad_events(ev)
-    pol = jnp.asarray(np.array([c[0] for c in cells], np.int32))
-    pf = jnp.asarray(np.array([c[1] for c in cells], np.int32))
-    cap = jnp.asarray(np.array([c[2] for c in cells], np.int32))
-    nv = jnp.full(b_pad, n_valid, jnp.int32)
-    ep = None
-    if evict_prefs is not None and any(p is not None for p in evict_prefs):
-        ep = np.zeros((b_pad, n_blocks), np.int32)
-        for i, p in enumerate(evict_prefs):
-            if p is not None:
-                ep[i, : len(p)] = np.asarray(p, np.int32)
-        ep = jnp.asarray(ep)
-    evs = tuple(jnp.asarray(getattr(ev, f)) for f in ("blk", "nxt", "dt", "rl", "stride"))
-    if ep is None:
-        stacked, (cap, pol, pf, nv), evs = _shard_lanes(
-            _stack_states(states), (cap, pol, pf, nv), evs, b_pad, kernels)
-    else:
-        stacked, (cap, pol, pf, nv, ep), evs = _shard_lanes(
-            _stack_states(states), (cap, pol, pf, nv, ep), evs, b_pad, kernels)
-    out_states, outs = _run_events(stacked, *evs, cap, pol, pf, nv, ep, kernels)
+    with obs.span("simulator.stage"):
+        n_blocks = states[0].resident.shape[0]
+        b_real = len(cells)
+        # lane buckets {1, 8, 16, ...}: single runs stay cheap, sweeps share compiles
+        b_pad = 1 if b_real == 1 else _bucket_pow2(b_real, 8)
+        cells = list(cells) + [(POLICY_IDS[_INERT[0]], PREFETCH_IDS[_INERT[1]], n_blocks + 1)] * (b_pad - b_real)
+        states = states + [init_state(n_blocks)] * (b_pad - b_real)
+        ev = _pad_events(ev)
+        pol = jnp.asarray(np.array([c[0] for c in cells], np.int32))
+        pf = jnp.asarray(np.array([c[1] for c in cells], np.int32))
+        cap = jnp.asarray(np.array([c[2] for c in cells], np.int32))
+        nv = jnp.full(b_pad, n_valid, jnp.int32)
+        ep = None
+        if evict_prefs is not None and any(p is not None for p in evict_prefs):
+            ep = np.zeros((b_pad, n_blocks), np.int32)
+            for i, p in enumerate(evict_prefs):
+                if p is not None:
+                    ep[i, : len(p)] = np.asarray(p, np.int32)
+            ep = jnp.asarray(ep)
+        evs = tuple(jnp.asarray(getattr(ev, f)) for f in ("blk", "nxt", "dt", "rl", "stride"))
+        if ep is None:
+            stacked, (cap, pol, pf, nv), evs = _shard_lanes(
+                _stack_states(states), (cap, pol, pf, nv), evs, b_pad, kernels)
+        else:
+            stacked, (cap, pol, pf, nv, ep), evs = _shard_lanes(
+                _stack_states(states), (cap, pol, pf, nv, ep), evs, b_pad, kernels)
+    with obs.span("simulator.dispatch"):
+        out_states, outs = _run_events(stacked, *evs, cap, pol, pf, nv, ep, kernels)
     return out_states, outs, b_real
 
 
@@ -740,20 +745,22 @@ def _decompress_outs(outs_lane: dict, ev: Events) -> dict:
     so per-access values are scattered to ``dt + k*stride`` rather than
     repeated contiguously."""
     e = len(ev.blk)
-    fault = np.zeros(ev.n_access, bool)
-    thrash = np.zeros(ev.n_access, np.int32)
-    ev_fault = np.asarray(outs_lane["fault"])[:e]
-    ev_thrash = np.asarray(outs_lane["thrash"])[:e]
-    ev_we = np.asarray(outs_lane["was_evicted"])[:e]
-    fault[ev.dt] = ev_fault
-    thrash[ev.dt] = ev_thrash
-    was_evicted = np.zeros(ev.n_access, bool)
-    intra = np.arange(int(ev.rl.sum())) - np.repeat(np.cumsum(ev.rl) - ev.rl, ev.rl)
-    pos = np.repeat(ev.dt, ev.rl) + intra * np.repeat(ev.stride, ev.rl)
-    was_evicted[pos] = np.repeat(ev_we, ev.rl)
+    ev_fault = obs.to_host(outs_lane["fault"], "simulator.outs")[:e]
+    ev_thrash = obs.to_host(outs_lane["thrash"], "simulator.outs")[:e]
+    ev_we = obs.to_host(outs_lane["was_evicted"], "simulator.outs")[:e]
+    with obs.span("simulator.decompress"):
+        fault = np.zeros(ev.n_access, bool)
+        thrash = np.zeros(ev.n_access, np.int32)
+        fault[ev.dt] = ev_fault
+        thrash[ev.dt] = ev_thrash
+        was_evicted = np.zeros(ev.n_access, bool)
+        intra = np.arange(int(ev.rl.sum())) - np.repeat(np.cumsum(ev.rl) - ev.rl, ev.rl)
+        pos = np.repeat(ev.dt, ev.rl) + intra * np.repeat(ev.stride, ev.rl)
+        was_evicted[pos] = np.repeat(ev_we, ev.rl)
     return {"fault": fault, "thrash": thrash, "was_evicted": was_evicted}
 
 
+@obs.spanned("simulator.run_segment")
 def run_segment(
     state: SimState,
     blocks: np.ndarray,
@@ -783,22 +790,31 @@ def run_segment(
     the ``REPRO_SIM_KERNELS`` env default) — counters are bit-identical
     either way (see :func:`_evict_fit`).
     """
-    state = _ensure_key(state)
+    with obs.span("simulator.stage"):
+        state = _ensure_key(state)
     blocks = np.asarray(blocks)
     next_use = np.asarray(next_use)
     cell = (POLICY_IDS[policy], PREFETCH_IDS[prefetch], int(capacity))
-    for periodic in (True, False):
-        ev = compress_events(blocks, next_use, periodic=periodic)
+
+    def scan(periodic: bool):
+        """One pass; ``None`` where the periodic aggregates diverged."""
+        with obs.span("simulator.compress"):
+            ev = compress_events(blocks, next_use, periodic=periodic)
         if ev.n_access == 0:
             z = np.zeros(0)
             return state, {"fault": z.astype(bool), "thrash": z.astype(np.int32), "was_evicted": z.astype(bool)}
         out_states, outs, _ = _run_cells([state], ev, [cell], n_valid,
                                          None if evict_pref is None else [evict_pref], kernels)
         lane = _lane(outs, 0)
-        if periodic and (ev.stride > 1).any() and bool(np.asarray(lane["pfault"]).any()):
-            continue  # divergence: a merged occurrence may have faulted
-        st = _lane(out_states, 0)
-        return st, (_decompress_outs(lane, ev) if want_outs else None)
+        if periodic and (ev.stride > 1).any() and obs.to_host(lane["pfault"], "simulator.pfault").any():
+            return None  # divergence: a merged occurrence may have faulted
+        return _lane(out_states, 0), (_decompress_outs(lane, ev) if want_outs else None)
+
+    out = scan(periodic=True)
+    if out is None:
+        with obs.span("simulator.rerun"):
+            out = scan(periodic=False)
+    return out
 
 
 def _run_segment(state, blocks, next_use, n_blocks=None, capacity=None, policy=None, prefetch=None, n_valid=None, want_outs=True):
@@ -875,6 +891,7 @@ def run(
     )
 
 
+@obs.spanned("simulator.run_batch")
 def run_batch(
     trace: Trace,
     cells: list[tuple[str, str, float]],
@@ -899,21 +916,24 @@ def run_batch(
             capacity_for(trace.n_blocks, oversub),
         ))
     lane_seeds = seeds if seeds is not None else [seed] * len(cells)
-    states = [init_state(nb, s) for s in lane_seeds]
-    for periodic in (True, False):
-        ev = compress_events(blocks, nxt, periodic=periodic)
-        out_states, outs, b_real = _run_cells(states, ev, id_cells, trace.n_blocks, kernels=kernels)
-        if periodic and (ev.stride > 1).any() and bool(np.asarray(jnp.any(outs["pfault"]))):
-            continue  # some lane's periodic merge diverged: rerun all on RLE
-        break
+    with obs.span("simulator.stage"):
+        states = [init_state(nb, s) for s in lane_seeds]
+    with obs.span("simulator.compress"):
+        ev = compress_events(blocks, nxt, periodic=True)
+    out_states, outs, b_real = _run_cells(states, ev, id_cells, trace.n_blocks, kernels=kernels)
+    if (ev.stride > 1).any() and bool(obs.to_host(jnp.any(outs["pfault"]), "simulator.pfault")):
+        with obs.span("simulator.rerun"):  # some lane's periodic merge diverged: rerun all on RLE
+            with obs.span("simulator.compress"):
+                ev = compress_events(blocks, nxt, periodic=False)
+            out_states, outs, b_real = _run_cells(states, ev, id_cells, trace.n_blocks, kernels=kernels)
     # one host sync for the whole sweep
-    counters = jax.device_get({
+    counters = obs.to_host({
         "thrash_events": out_states.thrash_events,
         "faults": out_states.faults,
         "migrations": out_states.migrations,
         "zero_copy": out_states.zero_copy,
         "occupancy": out_states.occupancy,
-    })
+    }, "simulator.counters")
     return [
         {
             "pages_thrashed": int(counters["thrash_events"][i]) * PAGES_PER_BLOCK,
@@ -962,8 +982,10 @@ def run_segments_many(
     eps = evict_prefs if evict_prefs is not None else [None] * len(states)
     groups: dict = {}
     for i, (st, (blocks, next_use)) in enumerate(zip(states, segments)):
-        st = _ensure_key(st)
-        ev = compress_events(np.asarray(blocks), np.asarray(next_use), periodic=True)
+        with obs.span("simulator.stage"):
+            st = _ensure_key(st)
+        with obs.span("simulator.compress"):
+            ev = compress_events(np.asarray(blocks), np.asarray(next_use), periodic=True)
         if ev.n_access == 0:
             z = np.zeros(0)
             results[i] = (st, {"fault": z.astype(bool), "thrash": z.astype(np.int32), "was_evicted": z.astype(bool)})
@@ -977,10 +999,12 @@ def run_segments_many(
     def _rle_rerun(i, st):
         """Exact single-lane rerun on plain RLE events (shares the b_pad=1
         compile bucket with run/run_segment)."""
-        ev_r = compress_events(np.asarray(segments[i][0]), np.asarray(segments[i][1]))
-        o_st, o_outs, _ = _run_cells([st], ev_r, [cells[i]], n_valids[i],
-                                     None if eps[i] is None else [eps[i]], kernels)
-        return _lane(o_st, 0), (_decompress_outs(_lane(o_outs, 0), ev_r) if want_outs else None)
+        with obs.span("simulator.rerun"):
+            with obs.span("simulator.compress"):
+                ev_r = compress_events(np.asarray(segments[i][0]), np.asarray(segments[i][1]))
+            o_st, o_outs, _ = _run_cells([st], ev_r, [cells[i]], n_valids[i],
+                                         None if eps[i] is None else [eps[i]], kernels)
+            return _lane(o_st, 0), (_decompress_outs(_lane(o_outs, 0), ev_r) if want_outs else None)
 
     for (nb, e_len), lanes in groups.items():
         if len(lanes) < 4:
@@ -991,7 +1015,7 @@ def run_segments_many(
                 out_states, outs, _ = _run_cells([st], ev, [cells[i]], n_valids[i],
                                                  None if eps[i] is None else [eps[i]], kernels)
                 lane = _lane(outs, 0)
-                if (ev.stride > 1).any() and bool(np.asarray(lane["pfault"]).any()):
+                if (ev.stride > 1).any() and obs.to_host(lane["pfault"], "simulator.pfault").any():
                     results[i] = _rle_rerun(i, st)
                 else:
                     results[i] = (_lane(out_states, 0), _decompress_outs(lane, ev) if want_outs else None)
@@ -999,36 +1023,38 @@ def run_segments_many(
         # lane counts fall into power-of-two buckets (inert padding lanes:
         # empty no-op event streams, never migrate) so every round of a
         # sweep reuses one compiled scan per bucket
-        b_real = len(lanes)
-        b_pad = _bucket_pow2(b_real, 4)
-        idxs = [i for i, *_ in lanes]
-        pad_ev = Events(*(np.zeros(e_len, np.int32),) * 5, 0)
-        stacked = _stack_states([st for _, st, _, _ in lanes] + [init_state(nb)] * (b_pad - b_real))
-        arrs = [
-            jnp.asarray(np.stack([getattr(p, f) for *_, p in lanes] + [getattr(pad_ev, f)] * (b_pad - b_real)))
-            for f in ("blk", "nxt", "dt", "rl", "stride")
-        ]
-        pad_cell = (POLICY_IDS[_INERT[0]], PREFETCH_IDS[_INERT[1]], nb + 1)
-        cell_arr = [
-            jnp.asarray(np.array([cells[i][k] for i in idxs] + [pad_cell[k]] * (b_pad - b_real), np.int32))
-            for k in range(3)
-        ]
-        nv = jnp.asarray(np.array([n_valids[i] for i in idxs] + [nb] * (b_pad - b_real), np.int32))
-        ep = None
-        if any(eps[i] is not None for i in idxs):
-            ep_np = np.zeros((b_pad, nb), np.int32)
-            for j, i in enumerate(idxs):
-                if eps[i] is not None:
-                    ep_np[j, : len(eps[i])] = np.asarray(eps[i], np.int32)
-            ep = jnp.asarray(ep_np)
-        if ep is None:
-            stacked, lane_arrs, _ = _shard_lanes(stacked, (*arrs, *cell_arr, nv), (), b_pad, kernels)
-            *arrs, pol_a, pf_a, cap_a, nv = lane_arrs
-        else:
-            stacked, lane_arrs, _ = _shard_lanes(stacked, (*arrs, *cell_arr, nv, ep), (), b_pad, kernels)
-            *arrs, pol_a, pf_a, cap_a, nv, ep = lane_arrs
-        out_states, outs = _run_events_lanes(stacked, *arrs, cap_a, pol_a, pf_a, nv, ep, kernels)
-        pdiv = np.asarray(outs["pfault"]).any(axis=1)
+        with obs.span("simulator.stage"):
+            b_real = len(lanes)
+            b_pad = _bucket_pow2(b_real, 4)
+            idxs = [i for i, *_ in lanes]
+            pad_ev = Events(*(np.zeros(e_len, np.int32),) * 5, 0)
+            stacked = _stack_states([st for _, st, _, _ in lanes] + [init_state(nb)] * (b_pad - b_real))
+            arrs = [
+                jnp.asarray(np.stack([getattr(p, f) for *_, p in lanes] + [getattr(pad_ev, f)] * (b_pad - b_real)))
+                for f in ("blk", "nxt", "dt", "rl", "stride")
+            ]
+            pad_cell = (POLICY_IDS[_INERT[0]], PREFETCH_IDS[_INERT[1]], nb + 1)
+            cell_arr = [
+                jnp.asarray(np.array([cells[i][k] for i in idxs] + [pad_cell[k]] * (b_pad - b_real), np.int32))
+                for k in range(3)
+            ]
+            nv = jnp.asarray(np.array([n_valids[i] for i in idxs] + [nb] * (b_pad - b_real), np.int32))
+            ep = None
+            if any(eps[i] is not None for i in idxs):
+                ep_np = np.zeros((b_pad, nb), np.int32)
+                for j, i in enumerate(idxs):
+                    if eps[i] is not None:
+                        ep_np[j, : len(eps[i])] = np.asarray(eps[i], np.int32)
+                ep = jnp.asarray(ep_np)
+            if ep is None:
+                stacked, lane_arrs, _ = _shard_lanes(stacked, (*arrs, *cell_arr, nv), (), b_pad, kernels)
+                *arrs, pol_a, pf_a, cap_a, nv = lane_arrs
+            else:
+                stacked, lane_arrs, _ = _shard_lanes(stacked, (*arrs, *cell_arr, nv, ep), (), b_pad, kernels)
+                *arrs, pol_a, pf_a, cap_a, nv, ep = lane_arrs
+        with obs.span("simulator.dispatch"):
+            out_states, outs = _run_events_lanes(stacked, *arrs, cap_a, pol_a, pf_a, nv, ep, kernels)
+        pdiv = obs.to_host(outs["pfault"], "simulator.pfault").any(axis=1)
         for j, (i, st, ev, _) in enumerate(lanes):
             if pdiv[j]:
                 results[i] = _rle_rerun(i, st)  # periodic merge diverged
